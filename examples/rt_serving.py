@@ -12,11 +12,13 @@ event runtime validates the timing model.
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch import use_compile_cache
 from repro.runtime import AdmissionController, ServingTaskSpec, serving_task_to_rt, simulate
 from repro.serving import ServeConfig, ServingEngine
 
 
 def main():
+    print(f"compile cache: {use_compile_cache()}")
     ac = AdmissionController(gn_total=12)
 
     services = [

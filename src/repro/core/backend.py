@@ -13,8 +13,9 @@ with array kernels.  Two implementations exist:
 
 Selection, in precedence order: an explicit ``backend=`` argument to the
 batched APIs, :func:`set_backend`, the ``REPRO_RTA_BACKEND`` environment
-variable, else ``numpy``.  JAX is optional: selecting it without the
-package installed raises, and everything else keeps working on NumPy.
+variable, else ``numpy``.  A process that runs a model on the accelerator
+keeps ``numpy``: the x64 switch would turn the model's default-dtype
+intermediates into float64.
 """
 from __future__ import annotations
 
@@ -26,16 +27,8 @@ _VALID = ("numpy", "jax")
 _backend: str | None = None
 
 
-def _jax_available() -> bool:
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        return False
-    return True
-
-
 def available_backends() -> tuple[str, ...]:
-    return ("numpy", "jax") if _jax_available() else ("numpy",)
+    return _VALID
 
 
 def set_backend(name: str) -> str:
@@ -44,12 +37,8 @@ def set_backend(name: str) -> str:
     if name not in _VALID:
         raise ValueError(f"unknown RTA backend {name!r}; choose from {_VALID}")
     if name == "jax":
-        try:
-            import jax
-        except ImportError as err:  # pragma: no cover - env without jax
-            raise RuntimeError(
-                "jax backend requested but jax is not importable"
-            ) from err
+        import jax
+
         # The analysis is float64 throughout; without x64 JAX silently
         # truncates to float32 and the 1e-9 equivalence contract breaks.
         jax.config.update("jax_enable_x64", True)

@@ -1,25 +1,24 @@
 """Jitted public wrappers around the Pallas kernels.
 
 These adapt model-layer shapes to kernel layouts (GQA expansion, head
-flattening, block-size selection, padding) and fall through to interpret
-mode on CPU so the same call sites work on the dry-run host.
+flattening, block-size selection).  Kernels compile for the TPU unless the
+caller passes ``interpret=True`` (the CPU tests do); a shape the kernel
+cannot tile raises instead of silently taking another path.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from .flash_attention import flash_attention
 from .persistent_matmul import persistent_matmul
 from .selective_scan import selective_scan
 
-__all__ = ["pinned_matmul", "mha_flash", "mamba_scan", "on_tpu"]
+__all__ = ["pinned_matmul", "mha_flash", "mamba_scan"]
 
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# Budget for selective_scan's double-buffered abar/bx input blocks, under
+# v5e's 16 MiB default scoped VMEM (the rest holds y, c and the h state).
+_SCAN_VMEM_BUDGET = 8 << 20
+_SUBLANES = 8
 
 
 def _pick_block(n: int, target: int) -> int:
@@ -29,12 +28,11 @@ def _pick_block(n: int, target: int) -> int:
     return max(b, 1)
 
 
-def pinned_matmul(x, w, *, n_bands: int = 8, interpret=None):
+def pinned_matmul(x, w, *, n_bands: int = 8, interpret: bool = False):
     """Persistent/pinned matmul with automatic block-size selection.
 
     ``n_bands`` is the task's virtual-SM band allocation (2·GN lanes run
     per band — Lemma 5.1's 2GN units)."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     m, k = x.shape
     _, n = w.shape
     bm = _pick_block(m, 128)
@@ -43,17 +41,20 @@ def pinned_matmul(x, w, *, n_bands: int = 8, interpret=None):
     # the tile space must split evenly over bands x 2 lanes
     while (m // bm) * (n // bn) % (n_bands * 2) and n_bands > 1:
         n_bands //= 2
-    if (m // bm) * (n // bn) % (n_bands * 2):
-        return x @ w  # degenerate tiling: fall back
+    tiles = (m // bm) * (n // bn)
+    if tiles % (n_bands * 2):
+        raise ValueError(
+            f"{x.shape} @ {w.shape}: {tiles} tile(s) of {bm}x{bn} cannot "
+            f"split over {n_bands} band(s) x 2 lanes"
+        )
     return persistent_matmul(
         x, w, n_bands=n_bands, block_m=bm, block_n=bn, block_k=bk,
         interpret=interpret,
     )
 
 
-def mha_flash(q, k, v, *, scale: float, window=None, interpret=None):
+def mha_flash(q, k, v, *, scale: float, window=None, interpret: bool = False):
     """q: [B, S, H, hd]; k/v: [B, S, Hkv, hd] -> [B, S, H*hd]."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     b, s, h, hd = q.shape
     hkv = k.shape[2]
     if hkv != h:
@@ -70,12 +71,14 @@ def mha_flash(q, k, v, *, scale: float, window=None, interpret=None):
     return out.reshape(b, h, s, hd).transpose(0, 2, 1, 3).reshape(b, s, h * hd)
 
 
-def mamba_scan(abar, bx, c, *, interpret=None):
-    interpret = (not on_tpu()) if interpret is None else interpret
+def mamba_scan(abar, bx, c, *, interpret: bool = False):
+    """Time chunk sized to VMEM: each step holds a [d_state, d_block] f32
+    tile of abar and of bx, d_state padded to whole sublane tiles."""
     b, s, d, n = abar.shape
+    d_block = _pick_block(d, 256)
+    rows = -(-n // _SUBLANES) * _SUBLANES
+    per_step = 2 * 2 * rows * d_block * 4   # abar + bx, double-buffered
+    chunk = _pick_block(s, max(1, min(128, _SCAN_VMEM_BUDGET // per_step)))
     return selective_scan(
-        abar, bx, c,
-        chunk=_pick_block(s, 128),
-        d_block=_pick_block(d, 256),
-        interpret=interpret,
+        abar, bx, c, chunk=chunk, d_block=d_block, interpret=interpret,
     )

@@ -1,10 +1,15 @@
 """Selective-scan (Mamba SSM) Pallas TPU kernel.
 
 Computes  h_t = abar_t ⊙ h_{t-1} + bx_t ;  y_t = Σ_s h_t[d, s] · c_t[s]
-over a sequence chunk, with the recurrent state h [d_block, d_state] held
+over a sequence chunk, with the recurrent state h [d_state, d_block] held
 in VMEM scratch that persists across the sequential time-chunk grid axis —
 the [S, d, d_state] hidden is never materialized in HBM (the HBM-residency
 of that tensor is what sinks a naive XLA lowering; see models/mamba.py).
+
+Layout: d_state sits on the sublane axis and d on the 128-lane axis
+([B, S, N, D] inside the kernel, one XLA transpose of abar and bx), so a
+d_state of 16 fills two sublane tiles instead of being padded eightfold to
+128 lanes — which also ran jamba-52b widths out of VMEM.
 
 Grid: (batch, d_blocks, time_chunks); time is innermost (sequential).
 """
@@ -28,12 +33,10 @@ def _kernel(abar_ref, bx_ref, c_ref, y_ref, h_ref, *, chunk: int):
         h_ref[...] = jnp.zeros_like(h_ref)
 
     def step(t, h):
-        # h: [d_block, d_state]
-        a_t = abar_ref[0, 0, t]   # [d_block, d_state]
-        b_t = bx_ref[0, 0, t]     # [d_block, d_state]
-        c_t = c_ref[0, 0, t]      # [d_state]
-        h = a_t * h + b_t
-        y_ref[0, 0, t] = (h * c_t[None, :]).sum(axis=-1).astype(y_ref.dtype)
+        # h: [d_state, d_block]
+        h = abar_ref[0, t] * h + bx_ref[0, t]
+        y_t = (h * c_ref[0, t]).sum(axis=0, keepdims=True)  # c_t: [d_state, 1]
+        y_ref[0, pl.ds(t, 1), :] = y_t.astype(y_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -54,36 +57,26 @@ def selective_scan(
     """Returns y: [B, S, D] (the h-state contraction with c per step)."""
     b, s, d, n = abar.shape
     assert bx.shape == (b, s, d, n) and c.shape == (b, s, n)
-    if s % chunk != 0:
-        chunk = s
-    if d % d_block != 0:
-        d_block = d
-    n_chunks = s // chunk
-    n_dblocks = d // d_block
-    grid = (b, n_dblocks, n_chunks)
+    if s % chunk or d % d_block:
+        raise ValueError(
+            f"chunk {chunk} / d_block {d_block} must divide S={s} / D={d}"
+        )
+    grid = (b, d // d_block, s // chunk)
+    state_major = (0, 1, 3, 2)   # [B, S, D, N] -> [B, S, N, D]
 
-    # layout: time-chunked [B, n_chunks, chunk, D, N]
-    abar_r = abar.reshape(b, n_chunks, chunk, d, n)
-    bx_r = bx.reshape(b, n_chunks, chunk, d, n)
-    c_r = c.reshape(b, n_chunks, chunk, n)
-
-    y = pl.pallas_call(
+    blk = pl.BlockSpec((1, chunk, n, d_block), lambda bi, di, ti: (bi, ti, 0, di))
+    return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(
-                (1, 1, chunk, d_block, n), lambda bi, di, ti: (bi, ti, 0, di, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, chunk, d_block, n), lambda bi, di, ti: (bi, ti, 0, di, 0)
-            ),
-            pl.BlockSpec((1, 1, chunk, n), lambda bi, di, ti: (bi, ti, 0, 0)),
+            blk,
+            blk,
+            pl.BlockSpec((1, chunk, n, 1), lambda bi, di, ti: (bi, ti, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, chunk, d_block), lambda bi, di, ti: (bi, ti, 0, di)
+            (1, chunk, d_block), lambda bi, di, ti: (bi, ti, di)
         ),
-        out_shape=jax.ShapeDtypeStruct((b, n_chunks, chunk, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((b, s, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n, d_block), jnp.float32)],
         interpret=interpret,
-    )(abar_r, bx_r, c_r)
-    return y.reshape(b, s, d)
+    )(abar.transpose(state_major), bx.transpose(state_major), c[..., None])

@@ -9,6 +9,7 @@ are replicated across pods and gradient all-reduce crosses the pod axis.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh"]
 
@@ -16,10 +17,11 @@ __all__ = ["make_production_mesh", "make_host_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh with the production axis names (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

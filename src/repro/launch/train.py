@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.cache import use_compile_cache
 from repro.models import Model
 from repro.train.checkpoint import save_checkpoint
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
@@ -91,6 +92,7 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
+    print(f"compile cache: {use_compile_cache()}")
     losses = train(args.arch, args.smoke, args.steps, args.batch, args.seq,
                    args.lr, args.ckpt_dir)
     print(f"first-10 mean {sum(losses[:10])/10:.4f} -> "

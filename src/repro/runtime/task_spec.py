@@ -2,26 +2,25 @@
 
 A :class:`ServingTask` wraps one model (an assigned architecture) serving
 periodic inference requests with a hard deadline.  Its RTGPU segments are
-derived from the *dry-run roofline terms* (DESIGN.md §5.3):
+derived from the measured model step and the dry-run terms (DESIGN.md §5.3):
 
   CPU segments     host pre/post-processing (tokenize / detokenize /
                    sampling) — measured or estimated ms,
   memory segments  host↔device transfer of the request tokens and result
                    logits over PCIe (non-preemptive, single channel),
-  GPU segment      the model step: GW = roofline step-time × one slice-lane
+  GPU segment      the model step: GW = measured step time × one slice-lane
                    (so Lemma 5.1's GW/(2GN) reproduces the N-slice time),
                    GL = collective+dispatch critical path, α from the
                    step's dominant-resource kernel type (Fig. 6 table).
 
-So the scheduler consumes exactly the artifact the dry-run produces.
+The step time is required: it is measured on the device the service runs
+on (chip_smoke.py times the warm decode step), never estimated here.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 from repro.core import INTERLEAVE_RATIO_MAX, GpuSegment, RTTask
-from repro.roofline import HBM_BW, PEAK_FLOPS
 
 __all__ = ["ServingTaskSpec", "serving_task_to_rt"]
 
@@ -38,8 +37,8 @@ class ServingTaskSpec:
     deadline_ms: float
     batch: int
     seq_len: int                 # context length per request
+    roofline_step_s: float       # per-chip step time (1 slice), measured
     new_tokens: int = 1          # decode steps per request (m-1 GPU segments)
-    roofline_step_s: Optional[float] = None  # per-chip step time (1 slice)
     collective_s: float = 0.0
     dominant: str = "compute_s"  # dry-run dominant term -> kernel type
     vocab: int = 32000
@@ -73,11 +72,7 @@ def serving_task_to_rt(spec: ServingTaskSpec) -> RTTask:
     # accelerator: one decode step per generated token
     ktype = _DOMINANT_TO_KTYPE.get(spec.dominant, "compute")
     alpha = INTERLEAVE_RATIO_MAX[ktype]
-    step_s = spec.roofline_step_s
-    if step_s is None:
-        # fallback: bandwidth-bound decode estimate
-        step_s = spec.batch * spec.vocab * 2 / HBM_BW
-    gw_ms = step_s * 1000.0 * 2.0  # GW at ONE virtual lane (2 lanes/slice)
+    gw_ms = spec.roofline_step_s * 1000.0 * 2.0  # GW at ONE virtual lane (2 lanes/slice)
     gl_ms = max(spec.collective_s * 1000.0, 0.02)
     gpu = [
         GpuSegment(

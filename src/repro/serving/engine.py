@@ -142,10 +142,13 @@ class ServingEngine:
                 )
             extra.append(enc_embeds)
 
+        # timings end in block_until_ready: they measure the device, not
+        # the enqueue of an asynchronous dispatch
         t0 = time.perf_counter()
         logits, caches = self._prefill(
             self.params, jnp.asarray(prompts), caches, *extra
         )
+        logits.block_until_ready()
         prefill_s = time.perf_counter() - t0
 
         out = np.zeros((b, max_new_tokens), np.int32)
@@ -156,6 +159,7 @@ class ServingEngine:
             out[:, i] = np.asarray(tok[:, 0])
             t1 = time.perf_counter()
             logits, caches = self._decode(self.params, tok, caches, cache_len)
+            logits.block_until_ready()
             decode_t.append(time.perf_counter() - t1)
             cache_len = cache_len + 1
             key, sub = jax.random.split(key)

@@ -1,5 +1,6 @@
 """Pallas-kernel validation: interpret=True vs the pure-jnp oracles,
-swept over shapes and dtypes (+ hypothesis property sweeps)."""
+swept over shapes and dtypes (+ hypothesis property sweeps).  Compiles for
+the TPU itself are in test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -51,12 +52,22 @@ class TestPersistentMatmul:
             np.testing.assert_allclose(o, outs[0], rtol=1e-6)
 
     def test_ops_fallback_for_odd_shapes(self):
+        """A tiling that cannot split over bands x 2 lanes raises; there is
+        no silent ``x @ w`` fallback."""
         kx, kw = jax.random.split(jax.random.PRNGKey(2))
         x = _rand(kx, (96, 80), jnp.float32)
         w = _rand(kw, (80, 112), jnp.float32)
+        with pytest.raises(ValueError, match="cannot split"):
+            ops.pinned_matmul(x, w, interpret=True)
+
+    def test_ops_pinned_matmul_matches_ref(self):
+        kx, kw = jax.random.split(jax.random.PRNGKey(6))
+        x = _rand(kx, (256, 256), jnp.float32)
+        w = _rand(kw, (256, 384), jnp.float32)
         got = ops.pinned_matmul(x, w, interpret=True)
         np.testing.assert_allclose(
-            np.asarray(got), np.asarray(x @ w), rtol=1e-4, atol=1e-4
+            np.asarray(got), np.asarray(ref.matmul_ref(x, w)),
+            rtol=1e-4, atol=1e-4,
         )
 
 
@@ -143,6 +154,25 @@ class TestSelectiveScan:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4
         )
+
+    @pytest.mark.parametrize("s,d,n", [(64, 256, 16), (48, 128, 4)])
+    def test_ops_mamba_scan_matches_ref(self, s, d, n):
+        """The wrapper's VMEM-sized chunk and d-block give the oracle's y."""
+        keys = jax.random.split(jax.random.PRNGKey(7), 3)
+        abar = jax.nn.sigmoid(_rand(keys[0], (1, s, d, n), jnp.float32))
+        bx = _rand(keys[1], (1, s, d, n), jnp.float32) * 0.1
+        c = _rand(keys[2], (1, s, n), jnp.float32)
+        got = ops.mamba_scan(abar, bx, c, interpret=True)
+        want = ref.selective_scan_ref(abar, bx, c)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4
+        )
+
+    def test_non_dividing_blocks_raise(self):
+        abar = jnp.ones((1, 40, 32, 4), jnp.float32)
+        with pytest.raises(ValueError, match="must divide"):
+            selective_scan(abar, abar, jnp.ones((1, 40, 4)), chunk=16,
+                           d_block=16, interpret=True)
 
     def test_matches_model_mamba_path(self):
         """Kernel result == models/mamba.ssm_scan_chunked (modulo d_skip)."""

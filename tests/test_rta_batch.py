@@ -249,15 +249,13 @@ class TestControllerEngines:
 
 class TestBackends:
     def test_numpy_always_available(self):
-        assert "numpy" in available_backends()
+        assert available_backends() == ("numpy", "jax")
 
     def test_unknown_backend_rejected(self):
         from repro.core.backend import set_backend
         with pytest.raises(ValueError):
             set_backend("cuda")
 
-    @pytest.mark.skipif("jax" not in available_backends(),
-                        reason="jax not installed")
     def test_jax_backend_equivalence_subprocess(self):
         """JAX backend agrees with the scalar path to 1e-9.
 

@@ -64,6 +64,14 @@ class TestAdmissionController:
             collective_s=0.0002, dominant="compute_s",
         )
 
+    def test_step_time_is_required(self):
+        """No bandwidth estimate stands in for a step time nobody measured."""
+        with pytest.raises(TypeError, match="roofline_step_s"):
+            serving_task_to_rt(ServingTaskSpec(
+                name="x", arch_id="qwen3-0.6b", period_ms=40.0,
+                deadline_ms=30.0, batch=8, seq_len=512,
+            ))
+
     def test_admits_until_capacity(self):
         ac = AdmissionController(gn_total=8)
         admitted = 0
