@@ -240,15 +240,17 @@ def attention_decode(params, cfg, x, cache: KVCache, cache_len,
     # scatter that XLA SPMD can only partition by replicating the whole
     # cache ("involuntary full rematerialization"); the broadcast-compare
     # select keeps the [B, S, kv, hd] buffer fully sharded.
-    write_mask = (
-        jnp.arange(s_max)[None, :] == jnp.clip(cache_len, 0, s_max - 1)[:, None]
-    )[:, :, None, None]  # [B, S, 1, 1]
+    with jax.named_scope("kv_update"):
+        write_mask = (
+            jnp.arange(s_max)[None, :]
+            == jnp.clip(cache_len, 0, s_max - 1)[:, None]
+        )[:, :, None, None]  # [B, S, 1, 1]
 
-    def write(buf, new):
-        return jnp.where(write_mask, new.astype(buf.dtype), buf)
+        def write(buf, new):
+            return jnp.where(write_mask, new.astype(buf.dtype), buf)
 
-    k = write(cache.k, k_new)
-    v = write(cache.v, v_new)
+        k = write(cache.k, k_new)
+        v = write(cache.v, v_new)
 
     kj = jnp.arange(s_max)[None, :]  # [1, S]
     valid = kj <= cache_len[:, None]  # include the just-written slot

@@ -73,15 +73,18 @@ def _ffn_apply(p, cfg, spec: LayerSpec, x):
         return x, 0.0
     h = apply_norm(p["norm2"], x, cfg.norm)
     if spec.ffn == "mlp":
-        return x + mlp(p["ffn"], h), 0.0
-    y, aux = moe_ffn(p["ffn"], cfg, h)
+        with jax.named_scope("mlp"):
+            return x + mlp(p["ffn"], h), 0.0
+    with jax.named_scope("moe"):
+        y, aux = moe_ffn(p["ffn"], cfg, h)
     return x + y, aux
 
 
 def block_train(p, cfg, spec: LayerSpec, x, window=None, enc_out=None):
     h = apply_norm(p["norm1"], x, cfg.norm)
     if spec.mixer == "attn":
-        x = x + attn.attention_train(p["mixer"], cfg, h, window)
+        with jax.named_scope("attention"):
+            x = x + attn.attention_train(p["mixer"], cfg, h, window)
     elif spec.mixer == "mamba":
         x = x + mb.mamba_train(p["mixer"], cfg, h)
     elif spec.mixer == "mlstm":
@@ -100,15 +103,16 @@ def block_prefill(p, cfg, spec: LayerSpec, x, cache, window=None, enc_out=None):
     h = apply_norm(p["norm1"], x, cfg.norm)
     new_cache = dict(cache)
     if spec.mixer == "attn":
-        y, kv = attn.attention_prefill(p["mixer"], cfg, h, window)
+        with jax.named_scope("attention"):
+            y, kv = attn.attention_prefill(p["mixer"], cfg, h, window)
         x = x + y
         # write prompt K/V into the fixed-size buffer
         buf = cache["kv"]
-        s = kv.k.shape[1]
-        new_cache["kv"] = attn.KVCache(
-            k=jax.lax.dynamic_update_slice(buf.k, kv.k.astype(buf.k.dtype), (0, 0, 0, 0)),
-            v=jax.lax.dynamic_update_slice(buf.v, kv.v.astype(buf.v.dtype), (0, 0, 0, 0)),
-        )
+        with jax.named_scope("kv_update"):
+            new_cache["kv"] = attn.KVCache(
+                k=jax.lax.dynamic_update_slice(buf.k, kv.k.astype(buf.k.dtype), (0, 0, 0, 0)),
+                v=jax.lax.dynamic_update_slice(buf.v, kv.v.astype(buf.v.dtype), (0, 0, 0, 0)),
+            )
     elif spec.mixer == "mamba":
         # run the train path and separately compute the final state
         y, state = _mamba_prefill(p["mixer"], cfg, h)
@@ -135,7 +139,8 @@ def block_decode(p, cfg, spec: LayerSpec, x, cache, cache_len, window=None):
     h = apply_norm(p["norm1"], x, cfg.norm)
     new_cache = dict(cache)
     if spec.mixer == "attn":
-        y, kv = attn.attention_decode(p["mixer"], cfg, h, cache["kv"], cache_len, window)
+        with jax.named_scope("attention"):
+            y, kv = attn.attention_decode(p["mixer"], cfg, h, cache["kv"], cache_len, window)
         x = x + y
         new_cache["kv"] = kv
     elif spec.mixer == "mamba":

@@ -111,7 +111,8 @@ class Model:
 
     def _logits(self, params, x):
         head = params.get("lm_head", params["embed"])["w"]
-        return x @ head.T
+        with jax.named_scope("lm_head"):
+            return x @ head.T
 
     # --------------------------------------------------------------- encoder
 

@@ -132,6 +132,19 @@ Metric name → emitting layer
 
   daemon_requests_total        counter    label cmd — protocol requests
   daemon_request_errors_total  counter    requests answered with an error
+
+``obs/compiles.py`` (JAX's ``jax.monitoring`` compile events; the
+listener is installed by the first ``metrics.enable()``):
+
+  jax_compile_seconds_total    counter    label stage=trace|lower|backend|
+                                          cache_load — seconds compiling
+                                          (cache_load is a part of backend)
+  jax_compiles_total           counter    label fun — executables built or
+                                          loaded from the persistent cache
+
+Data-path spans are profiler annotations (``jax.profiler``), not
+metrics: ``engine.*`` in ``serving/engine.py``, ``executor.*`` and
+``host.gc`` in ``runtime/executor.py``.
 """
 from .metrics import (  # noqa: F401
     MetricsRegistry,

@@ -344,11 +344,16 @@ def enabled() -> bool:
 
 
 def enable(fresh: bool = False) -> MetricsRegistry:
-    """Switch metrics on (optionally resetting all prior series)."""
+    """Switch metrics on (optionally resetting all prior series).  The
+    first call also installs the JAX compile counter
+    (:mod:`repro.obs.compiles`)."""
     global _REGISTRY
     if fresh:
         _LIVE.reset()
     _REGISTRY = _LIVE
+    from . import compiles
+
+    compiles.install()
     return _LIVE
 
 
@@ -436,3 +441,9 @@ class timed:
                     self.name, **self.labels
                 )
             inst.observe(self.ms)
+
+
+if _REGISTRY is _LIVE:      # REPRO_OBS=1: count compiles from the start
+    from . import compiles
+
+    compiles.install()

@@ -10,10 +10,19 @@ programmatically (:meth:`WallClockExecutor.add_service` /
 after its current job returns (jobs are never killed mid-flight).  All
 scheduling activity can be recorded into a :class:`repro.sched.EventTrace`
 (clock in seconds → ``us_per_unit=1e6``) for Chrome-trace export.
+
+:meth:`WallClockExecutor.run` also writes profiler spans
+(``jax.profiler.TraceAnnotation``, about a microsecond each while no
+profiler records): ``executor.run`` around the loop, ``executor.idle``
+around each polling sleep, ``executor.job`` around each job with its
+``service`` and ``wait_us`` (start minus due release: the wait of the
+same job in the ``EventTrace``), and ``host.gc`` around each pause of the
+garbage collector.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import heapq
 import time
 from typing import Callable, Optional, Sequence
@@ -91,6 +100,18 @@ class WallClockExecutor:
         """Run for ``duration_s``.  ``events`` is an optional churn script:
         ``(t, fn)`` pairs, each ``fn(executor)`` called once the wall clock
         passes ``t`` (e.g. ``lambda ex: ex.add_service(svc)``)."""
+        from jax.profiler import TraceAnnotation as span
+
+        gc_span = _GcSpan(span)
+        gc.callbacks.append(gc_span)
+        try:
+            with span("executor.run"):
+                return self._run(span, duration_s, events, poll_s)
+        finally:
+            gc.callbacks.remove(gc_span)
+            gc_span.close()
+
+    def _run(self, span, duration_s, events, poll_s) -> dict:
         t0 = time.perf_counter()
         script = sorted(events, key=lambda e: e[0]) if events else []
         script_idx = 0
@@ -133,13 +154,16 @@ class WallClockExecutor:
             while ready and id(ready[0][3]) not in alive:
                 heapq.heappop(ready)
             if not ready:
-                time.sleep(min(poll_s, duration_s - now))
+                with span("executor.idle"):
+                    time.sleep(min(poll_s, duration_s - now))
                 continue
             _, release, _, svc = heapq.heappop(ready)
             if id(svc) not in alive:
                 continue
             self._record("start", svc.name)
-            svc.run_job()
+            with span("executor.job", service=svc.name,
+                      wait_us=round((now - release) * 1e6, 3)):
+                svc.run_job()
             self._now = time.perf_counter() - t0
             resp = self._now - release
             svc.completed += 1
@@ -163,3 +187,26 @@ class WallClockExecutor:
                 agg["worst_response_ms"], s.worst_response_s * 1e3
             )
         return out
+
+
+class _GcSpan:
+    """A ``gc.callbacks`` hook: a ``host.gc`` span from each collection's
+    ``start`` to its ``stop``, with the generation collected."""
+
+    __slots__ = ("span", "open")
+
+    def __init__(self, span):
+        self.span = span
+        self.open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.open = self.span("host.gc", generation=info["generation"])
+            self.open.__enter__()
+        else:
+            self.close()
+
+    def close(self) -> None:
+        if self.open is not None:
+            self.open.__exit__(None, None, None)
+            self.open = None

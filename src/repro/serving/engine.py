@@ -10,6 +10,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.models import Model, ModelConfig
 
@@ -69,12 +70,14 @@ class ServingEngine:
                 kw["extra_embeds"] = extra[i]; i += 1
             if cfg.is_encoder_decoder:
                 kw["enc_embeds"] = extra[i]; i += 1
-            logits, caches, _ = model.prefill(params, tokens, caches, **kw)
+            with jax.named_scope("prefill"):
+                logits, caches, _ = model.prefill(params, tokens, caches, **kw)
             return logits, caches
 
         @jax.jit
         def decode_fn(params, token, caches, cache_len):
-            return model.decode_step(params, token, caches, cache_len)
+            with jax.named_scope("decode"):
+                return model.decode_step(params, token, caches, cache_len)
 
         self._prefill = prefill_fn
         self._decode = decode_fn
@@ -124,46 +127,59 @@ class ServingEngine:
     ) -> tuple[np.ndarray, dict]:
         b, s = prompts.shape
         assert b == self.serve.batch
-        key = key if key is not None else jax.random.PRNGKey(0)
-        caches = self.model.init_caches(b, self.serve.max_context)
-        extra = []
-        offset = 0
-        if self.cfg.n_patches:
-            if extra_embeds is None:
-                extra_embeds = jnp.zeros(
-                    (b, self.cfg.n_patches, self.cfg.d_model), jnp.float32
-                )
-            extra.append(extra_embeds)
-            offset = self.cfg.n_patches
-        if self.cfg.is_encoder_decoder:
-            if enc_embeds is None:
-                enc_embeds = jnp.zeros(
-                    (b, self.cfg.enc_ctx, self.cfg.d_model), jnp.float32
-                )
-            extra.append(enc_embeds)
+        # Each span names the RTGPU segment its work belongs to (the chain
+        # ``runtime.task_spec.serving_task_to_rt`` builds): ``cpu`` for host
+        # work, ``copy`` for host<->device transfers, ``device`` for a
+        # program from dispatch to ``block_until_ready``.
+        with span("engine.generate", batch=b, new_tokens=max_new_tokens):
+            with span("engine.init_caches", segment="cpu"):
+                key = key if key is not None else jax.random.PRNGKey(0)
+                caches = self.model.init_caches(b, self.serve.max_context)
+                extra = []
+                offset = 0
+                if self.cfg.n_patches:
+                    if extra_embeds is None:
+                        extra_embeds = jnp.zeros(
+                            (b, self.cfg.n_patches, self.cfg.d_model),
+                            jnp.float32)
+                    extra.append(extra_embeds)
+                    offset = self.cfg.n_patches
+                if self.cfg.is_encoder_decoder:
+                    if enc_embeds is None:
+                        enc_embeds = jnp.zeros(
+                            (b, self.cfg.enc_ctx, self.cfg.d_model),
+                            jnp.float32)
+                    extra.append(enc_embeds)
 
-        # timings end in block_until_ready: they measure the device, not
-        # the enqueue of an asynchronous dispatch
-        t0 = time.perf_counter()
-        logits, caches = self._prefill(
-            self.params, jnp.asarray(prompts), caches, *extra
-        )
-        logits.block_until_ready()
-        prefill_s = time.perf_counter() - t0
+            # timings end in block_until_ready: they measure the device, not
+            # the enqueue of an asynchronous dispatch
+            t0 = time.perf_counter()
+            with span("engine.upload", segment="copy"):
+                tokens = jnp.asarray(prompts)
+            with span("engine.prefill", segment="device"):
+                logits, caches = self._prefill(
+                    self.params, tokens, caches, *extra)
+                logits.block_until_ready()
+            prefill_s = time.perf_counter() - t0
 
-        out = np.zeros((b, max_new_tokens), np.int32)
-        cache_len = jnp.full((b,), s + offset, jnp.int32)
-        tok = self._sample(key, logits[:, -1, :])[:, None]
-        decode_t = []
-        for i in range(max_new_tokens):
-            out[:, i] = np.asarray(tok[:, 0])
-            t1 = time.perf_counter()
-            logits, caches = self._decode(self.params, tok, caches, cache_len)
-            logits.block_until_ready()
-            decode_t.append(time.perf_counter() - t1)
-            cache_len = cache_len + 1
-            key, sub = jax.random.split(key)
-            tok = self._sample(sub, logits[:, -1, :])[:, None]
+            with span("engine.sample", segment="cpu", step=0):
+                out = np.zeros((b, max_new_tokens), np.int32)
+                cache_len = jnp.full((b,), s + offset, jnp.int32)
+                tok = self._sample(key, logits[:, -1, :])[:, None]
+            decode_t = []
+            for i in range(max_new_tokens):
+                with span("engine.pull", segment="copy", step=i):
+                    out[:, i] = np.asarray(tok[:, 0])
+                t1 = time.perf_counter()
+                with span("engine.decode", segment="device", step=i):
+                    logits, caches = self._decode(
+                        self.params, tok, caches, cache_len)
+                    logits.block_until_ready()
+                decode_t.append(time.perf_counter() - t1)
+                with span("engine.sample", segment="cpu", step=i + 1):
+                    cache_len = cache_len + 1
+                    key, sub = jax.random.split(key)
+                    tok = self._sample(sub, logits[:, -1, :])[:, None]
         stats = {
             "prefill_s": prefill_s,
             "decode_s_per_tok": float(np.mean(decode_t)) if decode_t else 0.0,
