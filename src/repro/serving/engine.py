@@ -58,29 +58,68 @@ class ServingEngine:
         self.model = Model(cfg)
         key = jax.random.PRNGKey(seed)
         self.params = params if params is not None else self.model.init_params(key)
-        self._sample = sample_greedy if serve.sampler == "greedy" else sample_topk
-
+        sample = sample_greedy if serve.sampler == "greedy" else sample_topk
+        n_extra = int(bool(cfg.n_patches)) + int(cfg.is_encoder_decoder)
         model = self.model
+
+        # The sampler runs inside both programs, on the logits of the last
+        # position: each returns the next token, [B, 1] int32.  ``key`` is
+        # empty for greedy and ``(key,)`` for topk; the step's key is folded
+        # from it and the position whose logits are sampled, so the host
+        # passes one key per call and splits nothing.
+        def next_token(logits, key, pos):
+            sub = jax.random.fold_in(key[0], pos) if key else None
+            return sample(sub, logits[:, -1, :])[:, None]
 
         @jax.jit
         def prefill_fn(params, tokens, caches, *extra):
             kw = {}
-            i = 0
             if cfg.n_patches:
-                kw["extra_embeds"] = extra[i]; i += 1
+                kw["extra_embeds"] = extra[0]
             if cfg.is_encoder_decoder:
-                kw["enc_embeds"] = extra[i]; i += 1
+                kw["enc_embeds"] = extra[n_extra - 1]
             with jax.named_scope("prefill"):
                 logits, caches, _ = model.prefill(params, tokens, caches, **kw)
-            return logits, caches
+                pos = cfg.n_patches + tokens.shape[1] - 1
+                return next_token(logits, extra[n_extra:], pos), caches
 
         @jax.jit
-        def decode_fn(params, token, caches, cache_len):
+        def decode_fn(params, token, caches, cache_len, *key):
             with jax.named_scope("decode"):
-                return model.decode_step(params, token, caches, cache_len)
+                logits, caches = model.decode_step(
+                    params, token, caches, cache_len)
+                return next_token(logits, key, cache_len[0]), caches
+
+        # a job's fresh caches and zero patch / encoder embeddings, in one
+        # program per (batch, max_context)
+        @jax.jit
+        def alloc_fn():
+            b = serve.batch
+            zeros = []
+            if cfg.n_patches:
+                zeros.append(jnp.zeros((b, cfg.n_patches, cfg.d_model),
+                                       jnp.float32))
+            if cfg.is_encoder_decoder:
+                zeros.append(jnp.zeros((b, cfg.enc_ctx, cfg.d_model),
+                                       jnp.float32))
+            return model.init_caches(b, serve.max_context), zeros
 
         self._prefill = prefill_fn
         self._decode = decode_fn
+        self._alloc = alloc_fn
+        # topk's key when the caller gives none; greedy takes no key
+        self._key0 = None if serve.sampler == "greedy" else (
+            jax.random.PRNGKey(0))
+        self._cache_lens: dict[int, jax.Array] = {}
+
+    def _cache_len(self, n: int) -> jax.Array:
+        """``[batch]`` int32 on the device, all ``n``: made once per length
+        (every job of a service repeats the same lengths), so a decode step
+        adds no eager op."""
+        if n not in self._cache_lens:
+            self._cache_lens[n] = jax.device_put(
+                np.full((self.serve.batch,), n, np.int32))
+        return self._cache_lens[n]
 
     # ---- online-scheduler registration --------------------------------------
 
@@ -133,23 +172,13 @@ class ServingEngine:
         # program from dispatch to ``block_until_ready``.
         with span("engine.generate", batch=b, new_tokens=max_new_tokens):
             with span("engine.init_caches", segment="cpu"):
-                key = key if key is not None else jax.random.PRNGKey(0)
-                caches = self.model.init_caches(b, self.serve.max_context)
-                extra = []
-                offset = 0
-                if self.cfg.n_patches:
-                    if extra_embeds is None:
-                        extra_embeds = jnp.zeros(
-                            (b, self.cfg.n_patches, self.cfg.d_model),
-                            jnp.float32)
-                    extra.append(extra_embeds)
-                    offset = self.cfg.n_patches
-                if self.cfg.is_encoder_decoder:
-                    if enc_embeds is None:
-                        enc_embeds = jnp.zeros(
-                            (b, self.cfg.enc_ctx, self.cfg.d_model),
-                            jnp.float32)
-                    extra.append(enc_embeds)
+                caches, extra = self._alloc()
+                if self.cfg.n_patches and extra_embeds is not None:
+                    extra[0] = extra_embeds
+                if self.cfg.is_encoder_decoder and enc_embeds is not None:
+                    extra[-1] = enc_embeds
+                keys = () if self._key0 is None else (
+                    self._key0 if key is None else key,)
 
             # timings end in block_until_ready: they measure the device, not
             # the enqueue of an asynchronous dispatch
@@ -157,29 +186,24 @@ class ServingEngine:
             with span("engine.upload", segment="copy"):
                 tokens = jnp.asarray(prompts)
             with span("engine.prefill", segment="device"):
-                logits, caches = self._prefill(
-                    self.params, tokens, caches, *extra)
-                logits.block_until_ready()
+                tok, caches = self._prefill(
+                    self.params, tokens, caches, *extra, *keys)
+                tok.block_until_ready()
             prefill_s = time.perf_counter() - t0
 
-            with span("engine.sample", segment="cpu", step=0):
-                out = np.zeros((b, max_new_tokens), np.int32)
-                cache_len = jnp.full((b,), s + offset, jnp.int32)
-                tok = self._sample(key, logits[:, -1, :])[:, None]
+            out = np.zeros((b, max_new_tokens), np.int32)
+            start = s + self.cfg.n_patches
             decode_t = []
             for i in range(max_new_tokens):
                 with span("engine.pull", segment="copy", step=i):
-                    out[:, i] = np.asarray(tok[:, 0])
+                    out[:, i] = np.asarray(tok)[:, 0]
+                cache_len = self._cache_len(start + i)
                 t1 = time.perf_counter()
                 with span("engine.decode", segment="device", step=i):
-                    logits, caches = self._decode(
-                        self.params, tok, caches, cache_len)
-                    logits.block_until_ready()
+                    tok, caches = self._decode(
+                        self.params, tok, caches, cache_len, *keys)
+                    tok.block_until_ready()
                 decode_t.append(time.perf_counter() - t1)
-                with span("engine.sample", segment="cpu", step=i + 1):
-                    cache_len = cache_len + 1
-                    key, sub = jax.random.split(key)
-                    tok = self._sample(sub, logits[:, -1, :])[:, None]
         stats = {
             "prefill_s": prefill_s,
             "decode_s_per_tok": float(np.mean(decode_t)) if decode_t else 0.0,

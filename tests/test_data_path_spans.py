@@ -62,27 +62,52 @@ class TestEngineSpans:
 
         names = [s[0] for s in spans]
         assert names.count("engine.generate") == 1
+        # sampling runs inside the prefill and decode programs: no span of
+        # its own
         for name, count in (("engine.init_caches", 1), ("engine.upload", 1),
                             ("engine.prefill", 1), ("engine.decode", n),
-                            ("engine.pull", n), ("engine.sample", n + 1)):
+                            ("engine.pull", n), ("engine.sample", 0)):
             assert names.count(name) == count, name
         gen = next(s for s in spans if s[0] == "engine.generate")
         assert gen[3] == {"batch": 2, "new_tokens": n}
         steps = [s for s in spans if s[0] != "engine.generate"]
         kinds = {"engine.init_caches": "cpu", "engine.upload": "copy",
-                 "engine.prefill": "device", "engine.sample": "cpu",
-                 "engine.pull": "copy", "engine.decode": "device"}
+                 "engine.prefill": "device", "engine.pull": "copy",
+                 "engine.decode": "device"}
         for name, start, end, stats in steps:
             assert gen[1] <= start <= end <= gen[2], name
             assert stats["segment"] == kinds[name], name
         for name in ("engine.decode", "engine.pull"):
             assert [s[3]["step"] for s in steps if s[0] == name] == \
                 list(range(n))
-        assert [s[3]["step"] for s in steps if s[0] == "engine.sample"] == \
-            list(range(n + 1))
         # the steps follow one another: no two overlap
         for a, b in zip(steps, steps[1:]):
             assert a[2] <= b[1], (a[0], b[0])
+
+    def test_warm_generate_compiles_nothing(self):
+        eng = ServingEngine(tiny_cfg(), ServeConfig(max_context=32, batch=2))
+        prompts, n = _prompts(), 4
+        want, _ = eng.generate(prompts, max_new_tokens=n)   # compiles
+
+        def compiled():
+            series = metrics.registry().snapshot().get(
+                "jax_compiles_total", {}).get("series", {})
+            return sum(series.values())
+
+        try:
+            metrics.enable(fresh=True)
+            assert compiles.install()
+            eng.generate((prompts + 1) % 256, max_new_tokens=n)
+            got, _ = eng.generate(prompts, max_new_tokens=n)
+            warm = compiled()
+            # a new prompt length is a new prefill: the counter sees it
+            eng.generate(prompts[:, :8], max_new_tokens=n)
+            cold = compiled()
+        finally:
+            metrics.disable()
+        assert warm == 0
+        assert cold >= 1
+        np.testing.assert_array_equal(got, want)
 
     def test_model_scopes_name_the_compiled_ops(self):
         eng = ServingEngine(tiny_cfg(), ServeConfig(max_context=32, batch=2))

@@ -1,5 +1,7 @@
 """Integration tests: training reduces loss; checkpoint round-trip;
 serving engine decodes; data pipeline contracts."""
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -121,3 +123,93 @@ class TestServingEngine:
         np.testing.assert_allclose(
             np.asarray(full_logits), np.asarray(pre_logits), rtol=2e-4, atol=2e-4
         )
+
+
+# tiny configurations of every input path the engine's programs take
+SERVE_CFGS = {
+    "dense_gqa": tiny_cfg(),
+    "patches": dataclasses.replace(tiny_cfg(), n_patches=4),
+    "enc_dec": dataclasses.replace(tiny_cfg(), n_enc_layers=1, enc_ctx=8),
+}
+
+
+def _embeds(cfg, b, key):
+    """Random patch / encoder embeddings the configuration takes, as
+    ``generate``'s keyword arguments."""
+    kw = {}
+    if cfg.n_patches:
+        kw["extra_embeds"] = 0.02 * jax.random.normal(
+            key, (b, cfg.n_patches, cfg.d_model))
+    if cfg.is_encoder_decoder:
+        kw["enc_embeds"] = 0.02 * jax.random.normal(
+            jax.random.fold_in(key, 1), (b, cfg.enc_ctx, cfg.d_model))
+    return kw
+
+
+def eager_loop(model, params, prompts, n, max_context, fed=None, **kw):
+    """The serving loop written out op by op: prefill, then ``n`` decode
+    steps through the cache, each token the argmax of the last logits, or
+    the token in ``fed`` when given.  Returns the tokens ``[B, n]`` and the
+    logits each was chosen from ``[n, B, V]``."""
+    b, s = prompts.shape
+    cfg = model.cfg
+    caches = model.init_caches(b, max_context)
+    logits, caches, _ = model.prefill(params, jnp.asarray(prompts), caches,
+                                      **kw)
+    cache_len = jnp.full((b,), s + cfg.n_patches, jnp.int32)
+    toks, seen = [], []
+    for i in range(n):
+        last = logits[:, -1, :]
+        tok = (jnp.argmax(last, axis=-1).astype(jnp.int32) if fed is None
+               else jnp.asarray(fed[:, i]))
+        toks.append(np.asarray(tok))
+        seen.append(np.asarray(last))
+        logits, caches = model.decode_step(params, tok[:, None], caches,
+                                           cache_len)
+        cache_len = cache_len + 1
+    return np.stack(toks, axis=1), np.stack(seen)
+
+
+class TestSamplingOnDevice:
+    B, S, N, CTX = 2, 10, 5, 32
+
+    @pytest.mark.parametrize("name", list(SERVE_CFGS))
+    def test_greedy_matches_the_eager_loop(self, name):
+        cfg = SERVE_CFGS[name]
+        eng = ServingEngine(cfg, ServeConfig(max_context=self.CTX,
+                                             batch=self.B), seed=3)
+        prompts = np.random.default_rng(4).integers(
+            0, cfg.vocab, (self.B, self.S)).astype(np.int32)
+        kw = _embeds(cfg, self.B, jax.random.PRNGKey(5))
+        got, _ = eng.generate(prompts, max_new_tokens=self.N, **kw)
+        want, _ = eager_loop(eng.model, eng.params, prompts, self.N,
+                             self.CTX, **kw)
+        np.testing.assert_array_equal(got, want)
+        if kw:   # the zero embeddings the engine allocates when left out
+            got, _ = eng.generate(prompts, max_new_tokens=self.N)
+            zeros = {k: jnp.zeros_like(v) for k, v in kw.items()}
+            want, _ = eager_loop(eng.model, eng.params, prompts, self.N,
+                                 self.CTX, **zeros)
+            np.testing.assert_array_equal(got, want)
+
+    def test_topk_draws_from_the_top_k(self):
+        cfg, k = tiny_cfg(), 40
+        eng = ServingEngine(cfg, ServeConfig(max_context=self.CTX,
+                                             batch=self.B, sampler="topk"),
+                            seed=3)
+        prompts = np.random.default_rng(4).integers(
+            0, cfg.vocab, (self.B, self.S)).astype(np.int32)
+        n = 12
+        key, other = jax.random.PRNGKey(7), jax.random.PRNGKey(8)
+        a, _ = eng.generate(prompts, max_new_tokens=n, key=key)
+        b, _ = eng.generate(prompts, max_new_tokens=n, key=key)
+        c, _ = eng.generate(prompts, max_new_tokens=n, key=other)
+        np.testing.assert_array_equal(a, b)
+        assert not np.array_equal(a, c)
+        for drawn in (a, c):
+            fed, seen = eager_loop(eng.model, eng.params, prompts, n,
+                                   self.CTX, fed=drawn)
+            np.testing.assert_array_equal(fed, drawn)
+            kth = np.sort(seen, axis=-1)[..., -k]            # [n, B]
+            picked = np.take_along_axis(seen, drawn.T[..., None], -1)[..., 0]
+            assert (picked >= kth).all()
