@@ -256,9 +256,26 @@ def attention_decode(params, cfg, x, cache: KVCache, cache_len,
     valid = kj <= cache_len[:, None]  # include the just-written slot
     if window is not None:
         valid &= kj > cache_len[:, None] - window
-    mask = valid[:, None, :]  # [B, 1, S]
-    out = _sdpa_small(q, k, v, mask, cfg.head_dim ** -0.5)
+    out = _sdpa_decode(q, k, v, valid, cfg.head_dim ** -0.5)
     return out @ params["wo"], KVCache(k=k, v=v)
+
+
+def _sdpa_decode(q, k, v, valid, scale):
+    """Attention of one query token per row, reading each KV head once:
+    q [B,1,H,hd] is split into [B, kv, group, hd] against the unexpanded
+    [B, S, kv, hd] cache, so no copy of K/V per query head is made.  The
+    cache is not reshaped, so a sequence-sharded cache stays sharded.
+    Logits and output accumulate in f32; ``valid`` [B, S] masks the cache."""
+    b, _, h, hd = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, hkv, h // hkv, hd)
+    logits = jnp.einsum("bhgd,bkhd->bhgk", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(valid[:, None, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs, v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(v.dtype).reshape(b, 1, h * hd)
 
 
 def cross_attention(params, cfg, x, enc_kv: KVCache):
