@@ -1,10 +1,14 @@
-"""Benchmark harness — one entry per paper table/figure.
+"""The paper's schedulability figures (Figs. 8–13), on the CPU.
 
-Prints ``name,value,derived`` CSV lines.  ``--full`` raises the taskset
-counts to the paper's 100/level (hours on this host); the default is a
-CI-scale pass.  The roofline entries read the dry-run artifacts
-(results/dryrun/*.json); run ``python -m repro.launch.dryrun --all`` first
-for the complete 40-combo table.
+Prints ``name,value,derived`` CSV lines: acceptance ratios against
+taskset utilization (Figs. 8–11) and the analysis against the executed
+system (Figs. 12–13).  ``--full`` raises the taskset counts to the paper's
+100 per level (hours on a CPU host); the default is a short pass.
+
+These are analysis results, not speed claims: the repo's one performance
+benchmark is ``bench/run.py``, on the chip.
+
+  PYTHONPATH=src python -m benchmarks.run [--full] [--only fig8,fig12]
 """
 from __future__ import annotations
 
@@ -14,11 +18,7 @@ import time
 
 #: every stage name `--only` accepts, in execution order; a typo'd name
 #: is an error up front, not a silently empty run
-STAGES = (
-    "fig4", "fig6", "fig8", "fig9", "fig10", "fig11", "fig12",
-    "churn", "rta", "federation", "scale", "engine", "preemption", "obs",
-    "recovery", "roofline", "roofline_multipod",
-)
+STAGES = ("fig8", "fig9", "fig10", "fig11", "fig12")
 
 
 def main(argv=None) -> int:
@@ -47,44 +47,13 @@ def main(argv=None) -> int:
         fn(*a, **kw)
         print(f"# {name} done in {time.time()-t:.1f}s", file=sys.stderr)
 
-    from benchmarks import (
-        churn_acceptance,
-        engine_throughput,
-        federation_acceptance,
-        fig4_kernel_scaling,
-        fig6_interleave,
-        fig12_system_validation,
-        obs_overhead,
-        preemption_acceptance,
-        recovery_acceptance,
-        roofline_table,
-        rta_throughput,
-        scale_acceptance,
-        sched_acceptance,
-    )
+    from benchmarks import fig12_system_validation, sched_acceptance
 
-    stage("fig4", fig4_kernel_scaling.run, rows)
-    stage("fig6", fig6_interleave.run, rows)
     stage("fig8", sched_acceptance.fig8, n_sets, rows)
     stage("fig9", sched_acceptance.fig9, n_sets, rows)
     stage("fig10", sched_acceptance.fig10, n_sets, rows)
     stage("fig11", sched_acceptance.fig11, n_sets, rows)
     stage("fig12", fig12_system_validation.run, max(4, n_sets // 2), rows=rows)
-    stage("churn", churn_acceptance.run, rows)
-    stage("rta", rta_throughput.run, rows)
-    stage("federation", federation_acceptance.run, rows)
-    # --full adds the 1e5-resident level (minutes); default tops at 1e4
-    stage("scale", scale_acceptance.run, rows, full=args.full)
-    # indexed-vs-reference events/sec gate + simulate_fleet wall report
-    stage("engine", engine_throughput.run, rows)
-    stage("preemption", preemption_acceptance.run, rows)
-    stage("obs", obs_overhead.run, rows)
-    # the paper-scale acceptance figure is a 100-resident pool; the
-    # CI-scale default keeps the journal build inside the stage budget
-    stage("recovery", recovery_acceptance.run, rows,
-          residents=100 if args.full else 30)
-    stage("roofline", roofline_table.run, rows)
-    stage("roofline_multipod", roofline_table.run, rows, mesh="2x16x16")
 
     print("name,value,derived")
     for name, value in rows:

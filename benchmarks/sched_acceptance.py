@@ -49,9 +49,8 @@ def acceptance(
     """acceptance[method][u] = accepted fraction.
 
     The RTGPU methods run on the batched frontier engine by default
-    (result-identical to the scalar DFS; see benchmarks/rta_throughput.py
-    for the measured speedup) — pass ``engine="dfs"`` for the scalar
-    reference path."""
+    (result-identical to the scalar DFS; ``tests/test_rta_batch.py`` holds
+    it) — pass ``engine="dfs"`` for the scalar reference path."""
     out: dict = {m: {} for m in methods}
     for u in utils:
         acc = {m: 0 for m in methods}

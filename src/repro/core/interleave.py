@@ -11,8 +11,7 @@ TPU adaptation (DESIGN.md §2): a "kernel type" maps to the dominant resource
 of a model step — MXU-bound (compute), HBM-bound (memory), VPU/gather-bound
 (branch) and transcendental/softmax-heavy (special).  The ratios below are
 the paper's measured maxima, used both by the taskset generator and by the
-runtime's step-time model; benchmarks/fig6_interleave.py re-derives them from
-the synthetic two-stream benchmark.
+runtime's step-time model.
 """
 from __future__ import annotations
 

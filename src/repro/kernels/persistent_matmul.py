@@ -17,8 +17,7 @@ the ``index_map`` assigns every (band, lane, step) its pinned tile so that
 
 mirroring Algorithm 1's `[0, N/2) / [N/2, N)` split.  Giving a task a subset
 of bands = assigning it 2·GN_i virtual SMs (Lemma 5.1's 2GN_i), and the
-band count plugs straight into the ``t = (C-L)/m + L`` timing model
-(benchmarks/fig4_kernel_scaling.py fits exactly this).
+band count plugs straight into the ``t = (C-L)/m + L`` timing model.
 
 The K dimension is accumulated in a VMEM scratch accumulator across the
 innermost grid axis (TPU grids execute sequentially — "revisiting" order).

@@ -60,15 +60,11 @@ def _qkv(params, cfg, x, positions, rope: bool = True):
     return q, k, v
 
 
-import os as _os
-
-# GQA layout strategy.  "expand" (default) broadcasts K/V to the full H
-# query heads so the head axis keeps its model-axis sharding end to end —
-# the (hkv, group) reshape of the "grouped" variant splits a sharded axis
-# and forces XLA to all-gather Q inside the attention loop (~120 GB/device
-# on qwen3 train_4k; see EXPERIMENTS.md §Perf iteration 1).  The env toggle
-# reproduces the pre-fix baseline for the perf log.
-_GQA_GROUPED = _os.environ.get("REPRO_FLASH_GQA_GROUPED", "0") == "1"
+# GQA layout of the training and prefill paths: K/V are expanded to the
+# full H query heads, so the head axis keeps its ``model``-axis sharding end
+# to end; a (hkv, group) reshape would split that sharded axis and make XLA
+# all-gather Q inside the attention loop.  Decode reads the unexpanded cache
+# through ``_sdpa_decode``.
 
 
 def _expand_kv(k, group: int):
@@ -81,28 +77,20 @@ def _expand_kv(k, group: int):
 def _sdpa_small(q, k, v, mask, scale):
     """Materialized-logits attention for short sequences / decode.
 
-    q: [B,Sq,H,hd]; k/v: [B,Sk,Hkv,hd]; GQA via head grouping.
+    q: [B,Sq,H,hd]; k/v: [B,Sk,Hkv,hd]; GQA by expanding K/V to H heads.
     mask: [B,Sq,Sk] or None."""
     b, sq, h, hd = q.shape
     hkv = k.shape[2]
     group = h // hkv
-    if not _GQA_GROUPED:
-        k = _expand_kv(k, group)
-        v = _expand_kv(v, group)
-        logits = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-        ) * scale
-        if mask is not None:
-            logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        return out.reshape(b, sq, h * hd)
-    q = q.reshape(b, sq, hkv, group, hd)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32) * scale
+    k = _expand_kv(k, group)
+    v = _expand_kv(v, group)
+    logits = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * scale
     if mask is not None:
-        logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
+        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     return out.reshape(b, sq, h * hd)
 
 
@@ -116,11 +104,9 @@ def _flash_sdpa(q, k, v, scale, window: Optional[int], q_block: int = 512,
     b, s, h, hd = q.shape
     hkv = k.shape[2]
     group = h // hkv
-    if not _GQA_GROUPED:
-        # head-sharding-preserving layout: expand K/V to H heads
-        k = _expand_kv(k, group)
-        v = _expand_kv(v, group)
-        hkv, group = h, 1
+    k = _expand_kv(k, group)
+    v = _expand_kv(v, group)
+    hkv, group = h, 1
     nq = s // q_block
     nk = s // kv_block
     qb = q.reshape(b, nq, q_block, hkv, group, hd)

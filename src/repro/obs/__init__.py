@@ -90,8 +90,7 @@ Metric name → emitting layer
   engine_gpu_ctx_charged_total counter    context-switch time charged to
                                           evicted kernels (model clock)
   engine_steps_total           counter    event steps executed (either
-                                          loop variant; the events/sec
-                                          numerator in BENCH_engine.json)
+                                          loop variant)
   engine_step_width            histogram  model-time width per step — a
                                           mass at 0 exposes same-timestamp
                                           cascades (the livelock guard's

@@ -18,8 +18,8 @@ hold references across enable/disable cycles.
 :class:`NullRegistry` whose instruments discard every write: the
 instrumented hot paths (admission, certification, the discrete-event
 engine) pay one early-returned function call when observability is off,
-which keeps all goldens and benchmarks byte-identical by default
-(asserted in ``tests/test_obs.py`` and ``benchmarks/obs_overhead.py``).
+which keeps all goldens byte-identical by default (asserted in
+``tests/test_obs.py``).
 Enable with :func:`enable` (or the ``REPRO_OBS=1`` environment variable
 at import time), read with :func:`registry`, export with
 ``registry().snapshot()`` / ``to_json()`` / ``to_prometheus()``.

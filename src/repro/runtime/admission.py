@@ -19,8 +19,8 @@ per-host rejection fallback over ``hosts`` identical instant-mode
 controllers.  Either way the wrapper keeps the original one-shot API
 (``admit`` / ``remove`` / ``current_taskset``) while inheriting the warm
 paths — pinned 1-D admission search, hint + view-table reuse on the grid
-fallback — so repeated admissions are far cheaper than re-running
-Algorithm 2 cold (see ``benchmarks/churn_acceptance.py``).
+fallback — so repeated admissions do not re-run Algorithm 2 cold, and
+decide as it does (``tests/test_sched.py``).
 """
 from __future__ import annotations
 

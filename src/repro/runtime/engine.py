@@ -252,8 +252,8 @@ class DiscreteEventEngine:
     State exposed to policies: ``jobs`` (member → job-or-None), ``now``,
     and ``record`` for trace emission in the engine's clock.  ``steps``
     counts event steps executed (both loop variants), cheap enough to
-    maintain unconditionally — benchmarks use it as the events/sec
-    numerator.
+    maintain unconditionally; the two loops must agree on it
+    (``tests/test_engine_indexed.py``).
 
     ``variant`` selects the loop: ``"indexed"`` / ``"reference"``; when
     ``None`` the ``REPRO_ENGINE`` env var decides (default

@@ -14,7 +14,8 @@ derived from the measured model step and the dry-run terms (DESIGN.md §5.3):
                    step's dominant-resource kernel type (Fig. 6 table).
 
 The step time is required: it is measured on the device the service runs
-on (chip_smoke.py times the warm decode step), never estimated here.
+on (``bench/harness.py`` times the warm decode step), never estimated
+here.
 """
 from __future__ import annotations
 

@@ -41,8 +41,8 @@ of a full grid search.  Only if that fails (and ``allow_realloc``) does it
 fall back to the full Algorithm 2 search, warm-started with the previous
 allocation as a ``hint`` and the persistent
 :class:`~repro.core.rta.AnalysisTables` view cache, so unchanged
-(task, GN) workload staircases are never rebuilt.  ``benchmarks/
-churn_acceptance.py`` measures the speedup versus the cold grid search.
+(task, GN) workload staircases are never rebuilt.  ``tests/test_sched.py``
+holds its decisions to those of the cold grid search.
 
 All mutating operations are transactional: the ledger and the view cache
 are forked, and only a *successful* decision adopts the forks — a rejected
@@ -54,12 +54,12 @@ admission sweep runs through :class:`repro.core.rta_batch.BatchAnalyzer`
 (all candidate GNs certified per vectorized task sweep) and the
 re-allocation fallback through ``grid_search_frontier``; decisions,
 allocations, and certified R̂ bounds are identical to ``engine="scalar"``
-(asserted over churn traces in ``tests/test_rta_batch.py``), the latency
-is not (``benchmarks/rta_throughput.py``).  One caveat: when the realloc
-search is *truncated* by ``max_candidates``, the frontier and the DFS may
-give up on different subtrees, so cross-engine identity is guaranteed
-only for non-truncated searches (the same caveat the scalar engine's own
-budget already carries for decision stability across budget values).
+(asserted over churn traces in ``tests/test_rta_batch.py``).  One
+caveat: when the realloc search is *truncated* by ``max_candidates``, the
+frontier and the DFS may give up on different subtrees, so cross-engine
+identity is guaranteed only for non-truncated searches (the same caveat
+the scalar engine's own budget already carries for decision stability
+across budget values).
 """
 from __future__ import annotations
 

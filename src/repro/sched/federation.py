@@ -20,9 +20,7 @@ the next host in the order, so fleet admission only fails once *every*
 host has rejected.  Admission is two-pass — the cheap pinned sweep across
 all hosts first, the expensive re-allocation search only afterwards and
 only on the ``realloc_hosts`` most-promising hosts — so fleet-scale admit
-latency stays in the batched-certification regime
-(``benchmarks/federation_acceptance.py`` asserts it beats the single-host
-cold scalar path).
+latency stays in the batched-certification regime.
 
 **Departure-imbalance migration.**  When a departure reclaims capacity
 and leaves the fleet imbalanced (max load fraction − min load fraction >
@@ -48,8 +46,8 @@ changes, and executes entirely inside the mode-change protocol:
 **Fleet bookkeeping.**  Task names are fleet-unique; the broker tracks
 each task's *active* host (where its jobs run) and any in-flight
 migration.  ``repro.runtime.simulate_fleet`` drives one broker under the
-multi-host discrete-event simulator; ``benchmarks/federation_acceptance.py``
-tracks admit latency versus host count.
+multi-host discrete-event simulator; ``tests/test_federation.py`` holds
+its runs to no missed deadline and no bound violation across migrations.
 
 **Vectorized placement.**  The built-in policies are scored in one
 batched NumPy sweep over per-host free-slice / speed arrays the broker

@@ -36,7 +36,7 @@ is_departing), keyed by fleet-unique task names routed to the owning
 shard.  Departure-imbalance migration stays *within* each leaf broker —
 cross-shard migration is a recorded follow-on (ROADMAP).  The
 discrete-event fleet simulator drives flat brokers; trees are the
-admission-path scale layer (``benchmarks/scale_acceptance.py``).
+admission-path scale layer (``tests/test_scale.py``).
 """
 from __future__ import annotations
 

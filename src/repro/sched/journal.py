@@ -46,9 +46,7 @@ recovery (see :mod:`repro.sched.recovery`).
 **Durability.**  The connection runs ``journal_mode=WAL`` with
 ``synchronous=FULL``: every ``append`` is one fsync'd sqlite transaction,
 atomic under power loss.  The fsync cost per record is exported as the
-``journal_fsync_seconds`` histogram;
-``benchmarks/recovery_acceptance.py`` gates the end-to-end journaled
-admission overhead at < 2x the in-memory mean.
+``journal_fsync_seconds`` histogram.
 
 **Compaction.**  :meth:`checkpoint` writes a full state snapshot (the
 shape :func:`repro.sched.recovery.serialize_state` produces) and deletes

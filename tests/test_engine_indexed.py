@@ -19,17 +19,28 @@ Four layers of evidence:
     registry, and step counts agree across variants (same trajectory ⇒
     same step sequence).
 """
+import dataclasses
 import json
+import math
 from pathlib import Path
 
+import numpy as np
 import pytest
 
-from repro.core import ChurnConfig, generate_churn_trace, golden_scenario
+from repro.core import (
+    ChurnConfig,
+    ChurnEvent,
+    GeneratorConfig,
+    generate_churn_trace,
+    generate_taskset,
+    golden_scenario,
+)
 from repro.obs import metrics
 from repro.runtime import simulate, simulate_churn, simulate_fleet
 from repro.runtime.engine import DiscreteEventEngine, SchedulingPolicy
 from repro.runtime.record_golden import record_scenario
-from repro.sched import EventTrace
+from repro.runtime.simulator import _FleetChurnPolicy
+from repro.sched import CapacityBroker, DynamicController, EventTrace
 
 GOLDEN_DIR = Path(__file__).parent / "golden"
 GOLDEN_FILES = sorted(GOLDEN_DIR.glob("*.json"))
@@ -185,6 +196,116 @@ else:  # pragma: no cover
         _check_fleet_equivalent(seed, gpu)
 
 
+# ---- broker-routed fleet churn at a hundred residents -----------------------
+
+
+def _fleet_pool(seed=5, n=16, gn=8):
+    """``n`` single-service shapes and the mean slices one takes alone on
+    a ``gn``-slice host."""
+    rng = np.random.default_rng(seed)
+    pool = [
+        dataclasses.replace(
+            generate_taskset(rng, 0.05, GeneratorConfig(n_tasks=1,
+                                                        n_subtasks=3))[0],
+            name=f"pool{i}")
+        for i in range(n)
+    ]
+    probe = DynamicController(gn, transition="instant")
+    allocs = []
+    for t in pool:
+        if probe.admit(t).admitted:
+            allocs.append(probe.allocation[t.name])
+            probe.release(t.name)
+    return pool, float(np.mean(allocs))
+
+
+def _fleet_hosts(level, g_mean, gn=8):
+    """30 % headroom, so that every admission and re-admission fits."""
+    return math.ceil(level * g_mean / gn * 1.3)
+
+
+def _fleet_churn(level, pool, seed=7):
+    """``level`` pool services admitted over [0, 400), then an eighth of
+    them released in [400, 700) and each re-admitted 80 later."""
+    rng = np.random.default_rng(seed)
+    events = [
+        ChurnEvent(time=round(400.0 * i / level, 6), kind="admit",
+                   name=f"svc{i}",
+                   task=dataclasses.replace(pool[i % len(pool)],
+                                            name=f"svc{i}"))
+        for i in range(level)
+    ]
+    for v in sorted(int(x) for x in rng.choice(level, size=level // 8,
+                                               replace=False)):
+        t = float(rng.uniform(400.0, 700.0))
+        events.append(ChurnEvent(time=t, kind="release", name=f"svc{v}"))
+        events.append(ChurnEvent(
+            time=t + 80.0, kind="admit", name=f"svc{v}",
+            task=dataclasses.replace(pool[v % len(pool)], name=f"svc{v}")))
+    events.sort(key=lambda e: (e.time, e.name))
+    return events
+
+
+def _fleet_engine(variant, level=100, trace=None):
+    pool, g_mean = _fleet_pool()
+    broker = CapacityBroker.build(
+        _fleet_hosts(level, g_mean), 8, transition="boundary",
+        engine="batch", trace=trace,
+    )
+    policy = _FleetChurnPolicy(_fleet_churn(level, pool), broker,
+                               np.random.default_rng(11),
+                               release_jitter=True, worst_case=False)
+    return DiscreteEventEngine(policy, trace=trace, variant=variant)
+
+
+def test_hundred_resident_fleet_churn_identical_across_variants():
+    """A hundred residents on broker-routed hosts with live churn: both
+    loops take the same steps and record the same events."""
+    runs = {}
+    for variant in VARIANTS:
+        trace = EventTrace()
+        eng = _fleet_engine(variant, trace=trace)
+        eng.run(800.0)
+        runs[variant] = (eng.steps, trace)
+    (ref_steps, ref_trace), (idx_steps, idx_trace) = (
+        runs["reference"], runs["indexed"])
+    assert ref_steps == idx_steps > 0
+    _assert_trace_equal(ref_trace, idx_trace, "hundred-resident fleet churn")
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_seg_kind_probes_per_step(variant):
+    """The reference loop probes a member's segment kind at most once per
+    member per step; the indexed loop keeps kinds itself and never
+    probes."""
+    eng = _fleet_engine(variant)
+    calls = 0
+    probe = eng.seg_kind
+
+    def counting(key):
+        nonlocal calls
+        calls += 1
+        return probe(key)
+
+    eng.seg_kind = counting
+    eng.run(800.0)
+    assert eng.steps > 0
+    if variant == "indexed":
+        assert calls == 0
+    else:
+        assert 0 < calls <= 100 * eng.steps
+
+
+def test_simulate_fleet_admits_every_service_with_headroom():
+    """``simulate_fleet`` on the default loop places all hundred services
+    of the churn above (its admissions, which all precede t=400)."""
+    pool, g_mean = _fleet_pool()
+    res = simulate_fleet(_fleet_churn(100, pool)[:100],
+                         _fleet_hosts(100, g_mean), 8, horizon=500.0, seed=1)
+    assert len(res.admitted) == 100
+    assert sum(res.jobs.values()) > 0
+
+
 # ---- zero-width-step livelock guard -----------------------------------------
 
 
@@ -261,9 +382,8 @@ def test_engine_step_metrics_recorded():
 
 
 def test_step_counter_equal_across_variants():
-    """Bit-identical trajectories must take the identical step sequence —
-    `engine.steps` is the benchmark's events/sec numerator, so the two
-    loops must agree on it exactly."""
+    """Bit-identical trajectories must take the identical step sequence,
+    so the two loops must agree on `engine.steps` exactly."""
     ts, alloc = _small_taskset(11)
     counts = {}
     for variant in VARIANTS:

@@ -195,6 +195,38 @@ class TestMigration:
         assert name not in broker.hosts[0].allocation
         assert broker.migration_log[-1].name == name
 
+    def test_instant_departures_drain_by_migration(self):
+        """Two 28-slice hosts, first-fit packing the arrivals onto host 0,
+        then its residents depart one by one in instant mode: the
+        imbalance moves services between the hosts, each move inline and
+        certified."""
+        broker = CapacityBroker.build(
+            2, 28, transition="instant", engine="batch",
+            placement="first_fit", imbalance_threshold=0.2,
+            max_migrations_per_event=1,
+        )
+        events = generate_churn_trace(
+            seed=2, horizon=2500.0,
+            config=ChurnConfig(mean_interarrival=110.0,
+                               lifetime_range=(3500.0, 7000.0),
+                               util_range=(0.02, 0.05),
+                               task_config=GeneratorConfig(n_subtasks=3)),
+        )
+        admitted = [ev.name for ev in events
+                    if ev.kind == "admit" and broker.admit(ev.task).admitted]
+        moved = 0
+        for name in admitted:
+            if broker.active_host(name) != 0:
+                continue                   # migrated away already
+            before = len(broker.migration_log)
+            assert broker.release(name)
+            moved += len(broker.migration_log) - before
+        assert moved > 0
+        assert not broker.migrating        # instant mode: nothing in flight
+        assert broker.allocation
+        for name in broker.allocation:
+            assert broker.bound(name) != math.inf
+
     def test_migration_not_started_when_target_rejects(self):
         broker = self._imbalanced_broker()
         # fill host 1 so no candidate certifies there

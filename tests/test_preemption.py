@@ -43,8 +43,10 @@ from repro.sched import (
 
 #: churn regime where slice capacity (not the GPU) is the binding
 #: constraint — the same regime the `preemptive_churn` golden and
-#: `benchmarks/preemption_acceptance.py` exercise (one source of truth)
-CAPACITY_BOUND = golden_scenario("preemptive_churn").churn
+#: `TestPreemptiveCertification::test_preset_stream_preempts_and_holds_bounds`
+#: exercise (one source of truth)
+PRESET = golden_scenario("preemptive_churn")
+CAPACITY_BOUND = PRESET.churn
 
 
 def _gpu_preempts(trace: EventTrace) -> int:
@@ -238,6 +240,52 @@ class TestPreemptiveCertification:
         assert len(rp.admitted) > len(rn.admitted)
         assert not rp.any_miss
         assert rp.bound_violations() == []
+
+    def test_preset_stream_admits_more_than_dedication(self):
+        """The `preemptive_churn` arrival stream offered to a dedicated
+        and a preemptive controller: the preemptive one accepts more."""
+        accepted = {}
+        for preemption in ("none", "priority"):
+            ctl = DynamicController(
+                PRESET.gn_total, transition="instant",
+                preemption=preemption,
+                gpu_ctx_overhead=PRESET.gpu_ctx_overhead,
+            )
+            accepted[preemption] = 0
+            for ev in generate_churn_trace(seed=PRESET.seed,
+                                           horizon=PRESET.churn_horizon,
+                                           config=CAPACITY_BOUND):
+                if ev.kind == "release":
+                    ctl.release(ev.name)
+                else:
+                    accepted[preemption] += ctl.admit(ev.task,
+                                                      t=ev.time).admitted
+        assert accepted["priority"] > accepted["none"] > 0
+
+    def test_preset_stream_preempts_and_holds_bounds(self):
+        """The same stream run end to end: a service admitted that
+        dedication rejects, at least one GPU preemption, no miss, no
+        bound violation, and a `BoundMonitor` that gauges every resident
+        and raises no `bound_violation` alert."""
+        from repro.obs import BoundMonitor
+
+        events = generate_churn_trace(seed=PRESET.seed,
+                                      horizon=PRESET.churn_horizon,
+                                      config=CAPACITY_BOUND)
+        rn = simulate_churn(events, PRESET.gn_total, horizon=PRESET.horizon,
+                            seed=PRESET.seed)
+        trace = EventTrace()
+        monitor = BoundMonitor()
+        rp = simulate_churn(events, PRESET.gn_total, horizon=PRESET.horizon,
+                            seed=PRESET.seed, preemption="priority",
+                            gpu_ctx_overhead=PRESET.gpu_ctx_overhead,
+                            trace=trace, monitor=monitor)
+        assert set(rp.admitted) - set(rn.admitted)
+        assert _gpu_preempts(trace) > 0
+        assert not rp.any_miss, rp.misses
+        assert rp.bound_violations() == []
+        assert not set(rp.admitted) - set(monitor.gauges())
+        assert not any(a.kind == "bound_violation" for a in monitor.alerts)
 
     def test_rejection_is_transactional_under_preemption(self):
         tasks = self._tasks(9, n=16)

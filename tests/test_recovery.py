@@ -1,5 +1,5 @@
 """Durable control plane: journal, crash recovery, daemon, and the
-exception-safety / atomic-artifact satellites.
+exception-safety satellite.
 
 The load-bearing property (ISSUE acceptance): for EVERY journal record
 boundary across a scripted admit → update → migrate → release churn, a
@@ -57,6 +57,14 @@ def _tasks(seed=0, util=0.5, n=6, m=3):
 
 def _task(seed, util, name):
     t = _tasks(seed=seed, util=util, n=1)[0]
+    return dataclasses.replace(t, name=name)
+
+
+def _small_task(seed, util, name):
+    """One service of two subtasks: the shape of a resident in the large
+    pools below."""
+    t = generate_taskset(np.random.default_rng(seed), util,
+                         GeneratorConfig(n_tasks=1, n_subtasks=2))[0]
     return dataclasses.replace(t, name=name)
 
 
@@ -123,6 +131,24 @@ class TestJournal:
         assert (back.task, back.alloc, back.staged_task, back.staged_alloc,
                 back.departing) == (e.task, e.alloc, e.staged_task,
                                     e.staged_alloc, e.departing)
+
+    def test_journal_does_not_change_decisions(self, tmp_path):
+        """The same twelve arrivals, with and without a journal attached:
+        every one admitted, at the same allocation and bounds."""
+        decisions = []
+        for journal in (None, Journal(str(tmp_path / "j.sqlite"))):
+            ctl = DynamicController(32, transition="instant",
+                                    journal=journal)
+            decisions.append([
+                (d.admitted, d.alloc, d.bounds) for d in (
+                    ctl.admit(_small_task(7 + i, 0.02, f"t{i}"))
+                    for i in range(12)
+                )
+            ])
+            if journal is not None:
+                journal.close()
+        assert all(admitted for admitted, _, _ in decisions[0])
+        assert decisions[0] == decisions[1]
 
     def test_journal_metrics_emitted(self, tmp_path):
         metrics.enable(fresh=True)
@@ -426,6 +452,26 @@ class TestRecertification:
         assert not report.alerts
         assert set(report.recert[0].values()) == {"exact"}
 
+    def test_resident_pool_recovers_exact(self, tmp_path):
+        """A 30-resident pool admitted through a journaled controller
+        (128 slices, pinned path only) comes back from a cold reopen of
+        the journal with the same residents and bounds, none
+        quarantined."""
+        path = str(tmp_path / "pool.sqlite")
+        j = Journal(path)
+        ctl = DynamicController(128, transition="instant", journal=j,
+                                allow_realloc=False, max_candidates=16)
+        for i in range(30):
+            dec = ctl.admit(_small_task(7 + i, 0.004, f"r{i}"))
+            assert dec.admitted, (i, dec.reason)
+        j.close()                                  # the process dies here
+        cold = Journal(path)
+        ctl2, report = recover_controller(cold)
+        cold.close()
+        assert not report.quarantined
+        assert sorted(ctl2.allocation) == sorted(ctl.allocation)
+        assert ctl2.bounds() == ctl.bounds()
+
     def test_config_drift_rejected_on_reattach(self):
         j = Journal(":memory:")
         DynamicController(8, transition="instant", journal=j)
@@ -542,42 +588,6 @@ class TestCallbackSafety:
         ctl = DynamicController(8, transition="instant", trace=trace)
         dec = ctl.admit(_task(0, 0.1, "a"))        # records through the trace
         assert dec.admitted and "a" in ctl.allocation
-
-
-# ---- atomic benchmark artifacts (satellite) ----------------------------------
-
-class TestAtomicBenchWrites:
-    def test_write_bench_atomic_and_clean(self, tmp_path):
-        sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-        try:
-            from _envelope import envelope, write_bench
-        finally:
-            sys.path.pop(0)
-        path = tmp_path / "BENCH_x.json"
-        write_bench(str(path), envelope("x", {"a": 1}, body={"v": 2}))
-        doc = json.loads(path.read_text())
-        assert doc["bench"] == "x" and doc["body"] == {"v": 2}
-        assert list(tmp_path.iterdir()) == [path]  # no tmp residue
-
-    def test_crash_mid_write_keeps_previous_artifact(self, tmp_path,
-                                                     monkeypatch):
-        sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-        try:
-            import _envelope
-        finally:
-            sys.path.pop(0)
-        path = tmp_path / "BENCH_y.json"
-        _envelope.write_bench(str(path), {"v": 1})
-
-        def explode(*a, **kw):
-            raise OSError("disk gone")
-
-        monkeypatch.setattr(_envelope.json, "dump", explode)
-        with pytest.raises(OSError):
-            _envelope.write_bench(str(path), {"v": 2})
-        monkeypatch.undo()
-        assert json.loads(path.read_text()) == {"v": 1}   # intact
-        assert list(tmp_path.iterdir()) == [path]         # tmp cleaned up
 
 
 # ---- daemon ------------------------------------------------------------------

@@ -97,6 +97,17 @@ class TestSearchEquivalence:
         _assert_same_result(d, f, f"hinted seed={seed}")
 
 
+    @pytest.mark.parametrize("util", [0.3, 0.6, 0.9, 1.2, 1.6])
+    def test_frontier_matches_dfs_under_node_budget(self, util):
+        """Table-1 generator defaults (N=5, M=5) on 10 slices with a
+        400-node budget: the two searches still agree."""
+        ts = generate_taskset(np.random.default_rng(0), util,
+                              GeneratorConfig())
+        d = grid_search_dfs(ts, 10, tightened=True, max_nodes=400)
+        f = grid_search_frontier(ts, 10, tightened=True, max_nodes=400)
+        _assert_same_result(d, f, f"budget u={util}")
+
+
 class TestAnalyzerEquivalence:
     @pytest.mark.parametrize("seed", range(6))
     def test_analyze_prefixes_bit_identical(self, seed):
@@ -185,6 +196,33 @@ class TestControllerEngines:
             b_stream, b_trace = self._replay(events, transition, "batch")
             assert s_stream == b_stream, (preset.name, transition)
             assert s_trace == b_trace, (preset.name, transition)
+
+    def test_fleet_scale_churn_admissions_identical(self):
+        """Small services arriving on 28 slices until about twenty are
+        resident, where the batched sweep is the controller's own choice:
+        batch == scalar decisions and bounds."""
+        events = generate_churn_trace(
+            seed=1, horizon=2500.0,
+            config=ChurnConfig(mean_interarrival=110.0,
+                               lifetime_range=(3500.0, 7000.0),
+                               util_range=(0.02, 0.05),
+                               task_config=GeneratorConfig(n_subtasks=3)),
+        )
+        streams, peak = [], 0
+        for engine in ("scalar", "batch"):
+            c = DynamicController(28, transition="instant", engine=engine)
+            stream = []
+            for ev in events:
+                if ev.kind == "release":
+                    c.release(ev.name)
+                    continue
+                dec = c.admit(ev.task, t=ev.time)
+                stream.append((ev.name, dec.admitted, None if dec.bounds
+                               is None else tuple(sorted(dec.bounds.items()))))
+                peak = max(peak, len(c.allocation))
+            streams.append(stream)
+        assert streams[0] == streams[1]
+        assert peak >= 18
 
     def test_engine_validation(self):
         with pytest.raises(ValueError):

@@ -104,6 +104,12 @@ class TestPlacementEquivalence:
         for seed in range(12):
             _assert_orders_identical(_random_fleet(seed))
 
+    def test_wide_fleet_states(self):
+        """48 hosts of mixed speeds, random occupancy, three drained."""
+        for seed in range(4):
+            _assert_orders_identical(_random_fleet(seed, n_hosts=48,
+                                                   drain=3))
+
     def test_weighted_honors_speed_classes(self):
         # slower host with more free slices must lose to a faster one
         # with fewer when free * speed says so — in both implementations
@@ -324,6 +330,26 @@ class TestBrokerTree:
         for n in names:
             assert tree.release(n)
         assert tree.residents == 0 and tree.capacity_in_use == 0
+
+    @pytest.mark.parametrize("level", [100, 1000])
+    def test_fill_with_headroom_admits_every_arrival(self, level):
+        """A tree provisioned with 30 % more slices than ``level`` pool
+        services take on a scratch host admits every one of them."""
+        pool = _pool(3, n=16)
+        probe = DynamicController(GN, transition="instant")
+        allocs = []
+        for t in pool:
+            if probe.admit(t).admitted:
+                allocs.append(probe.allocation[t.name])
+                probe.release(t.name)
+        n_hosts = int(np.ceil(level * np.mean(allocs) / GN * 1.3))
+        tree = BrokerTree.build(n_hosts, GN, transition="instant",
+                                engine="batch", migrate_on_departure=False)
+        for i in range(level):
+            dec = tree.admit(dataclasses.replace(pool[i % len(pool)],
+                                                 name=f"svc{i}"))
+            assert dec.admitted, (i, dec.reason)
+        assert tree.residents == level
 
     def test_digest_prunes_implausible_shards(self):
         tree = BrokerTree.build(8, GN, hosts_per_shard=4, fanout=4,

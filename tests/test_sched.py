@@ -203,6 +203,46 @@ class TestWarmStart:
                 assert dec.tried <= c.gn_total
 
 
+    def test_warm_admission_decides_as_cold_grid_search(self):
+        """Each arrival of a churn trace decided twice: by the warm
+        controller and by Algorithm 2 run cold on the same candidate set.
+        Where neither search hits its budget the two agree; the trace
+        reaches six residents; run end to end it misses no deadline and
+        violates no bound."""
+        from repro.core import TaskSet, analyze_rtgpu_plus, schedule
+
+        budget = 400
+        events = generate_churn_trace(
+            seed=0, horizon=6000.0,
+            config=ChurnConfig(mean_interarrival=250.0,
+                               lifetime_range=(2500.0, 5000.0),
+                               util_range=(0.05, 0.12),
+                               task_config=GeneratorConfig(n_subtasks=3)),
+        )
+        warm = DynamicController(10, transition="instant",
+                                 max_candidates=budget)
+        compared = largest = 0
+        for ev in events:
+            if ev.kind == "release":
+                warm.release(ev.name)
+                continue
+            cand = TaskSet.deadline_monotonic(
+                [warm.task(n) for n in warm.order()] + [ev.task])
+            cold = schedule(cand, 10, analyzer=analyze_rtgpu_plus,
+                            mode="grid", max_candidates=budget)
+            dec = warm.admit(ev.task)
+            if dec.admitted:
+                largest = max(largest, len(cand))
+            if cold.candidates_tried < budget and dec.tried < budget:
+                assert dec.admitted == cold.schedulable, ev.name
+                compared += 1
+        assert compared > 0
+        assert largest >= 6
+        res = simulate_churn(events, 10, 7000.0, seed=0)
+        assert not res.any_miss, res.misses
+        assert res.bound_violations() == []
+
+
 class TestTelemetry:
     def test_simulator_trace_records_and_exports(self):
         from repro.core import analyze_rtgpu_plus, schedule
